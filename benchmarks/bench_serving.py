@@ -1499,7 +1499,16 @@ def sharded_comparison(mesh_sizes=(1, 2, 4)) -> dict:
     """Tensor-parallel capacity sweep: fan each mesh size out to its own
     subprocess (``xla_force_host_platform_device_count`` only takes
     effect before jax imports, and this parent already imported jax) and
-    compare peak concurrency at fixed per-device KV HBM."""
+    compare peak concurrency at fixed per-device KV HBM.
+
+    A CPU rehearsal only: the children are pinned to the CPU, and on a
+    chip machine this parent already holds the chip. The four-chip path
+    is ``python chip_smoke.py --mesh 4``, one process."""
+    if jax.default_backend() == "tpu":
+        raise SystemExit(
+            "sharded_comparison fans CPU-pinned children out from a parent "
+            "that holds the TPU; on the chip run `python chip_smoke.py "
+            "--mesh 4` (one process drives every chip)")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     points = {}
     for m in mesh_sizes:

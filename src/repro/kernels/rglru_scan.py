@@ -19,10 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _kernel(a_ref, b_ref, h0_ref, h_ref, hlast_ref, carry_ref, *,
             block_t: int, num_t: int):
@@ -94,7 +90,7 @@ def rglru_scan(a, b, h0, *, block_t: int = 128, block_w: int = 128,
             jax.ShapeDtypeStruct((bsz, a.shape[2]), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

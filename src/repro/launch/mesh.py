@@ -9,19 +9,28 @@ parallelism over ICI, never over the cross-pod DCN).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: GSPMD propagates the
+    shardings and ``with_sharding_constraint`` accepts the logical-axis
+    hints (``make_mesh`` defaults to ``Explicit`` axes, which reject
+    both)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """A small mesh over the actually-present devices (tests, examples)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 # TPU v5e hardware constants used by the roofline analysis

@@ -2,7 +2,8 @@
 gateway — streamed tokens, backpressure, SLO classes — on the dense
 engine or the ACE edge/cloud cascade with --cascade.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m --reduced
+    PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m
+    PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m --no-reduced
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m --cascade
     PYTHONPATH=src python -m repro.launch.serve --rate 40 --policy shed
 
@@ -42,7 +43,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.models.model import LM
 from repro.serving import (CascadeServingEngine, EngineWedgedError,
                            FaultPlan, RequestJournal, ServingEngine,
-                           ServingGateway, enable_compile_cache,
+                           ServingGateway, enable_compile_cache, init_params,
                            recover_engine)
 
 
@@ -66,15 +67,15 @@ def _build_engine(cfg, args, fault_plan=None):
     if args.cascade:
         edge_cfg = edge_variant(cfg, layers=1)
         cloud, edge = LM(cfg, kv_chunk=32), LM(edge_cfg, kv_chunk=32)
-        cp, _ = cloud.init(jax.random.PRNGKey(0))
-        ep, _ = edge.init(jax.random.PRNGKey(1))
+        cp = init_params(cloud, jax.random.PRNGKey(0), mesh)
+        ep = init_params(edge, jax.random.PRNGKey(1), mesh)
         cascade = CascadeLM(edge, cloud,
                             thresholds=make_thresholds(hi=0.01, lo=0.001))
         return CascadeServingEngine(cascade, ep, cp, batch_slots=4,
                                     max_seq_len=96, fault_plan=fault_plan,
                                     mesh=mesh)
     lm = LM(cfg, kv_chunk=32)
-    params, _ = lm.init(jax.random.PRNGKey(0))
+    params = init_params(lm, jax.random.PRNGKey(0), mesh)
     return ServingEngine(lm, params, batch_slots=4, max_seq_len=96,
                          fault_plan=fault_plan, mesh=mesh)
 
@@ -121,12 +122,9 @@ async def _serve(args) -> None:
     journal = None
     gw_kw = {}
     if args.compile_cache:
-        # persistent executable cache keyed under the state dir: a
-        # supervised restart-from-snapshot replays warm_compile from disk
-        state_dir = args.state_dir or tempfile.mkdtemp(prefix="repro_serve_")
-        args.state_dir = state_dir
-        enable_compile_cache(os.path.join(state_dir, "compile_cache"))
-        print(f"compile cache: {os.path.join(state_dir, 'compile_cache')}")
+        # persistent executable cache at a fixed path: a supervised
+        # restart-from-snapshot (or a rerun) replays warm_compile from disk
+        print(f"compile cache: {enable_compile_cache()}")
     if args.supervise:
         state_dir = args.state_dir or tempfile.mkdtemp(
             prefix="repro_serve_")
@@ -189,7 +187,10 @@ async def _serve(args) -> None:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the config's reduced float32 variant; "
+                         "--no-reduced serves the published widths")
     ap.add_argument("--cascade", action="store_true")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=8)
@@ -213,12 +214,14 @@ def main() -> None:
     # mesh-aware serving (ISSUE 10)
     ap.add_argument("--mesh", type=int, default=1,
                     help="tensor-parallel ways on the 'model' mesh axis "
-                         "(device count must divide; on CPU export "
+                         "(must divide the device count and the KV heads; "
+                         "on CPU export "
                          "XLA_FLAGS=--xla_force_host_platform_device_count"
                          "=N first). 1 = single-device (default)")
     ap.add_argument("--compile-cache", action="store_true",
-                    help="persist compiled executables under --state-dir/"
-                         "compile_cache so restarts skip recompilation")
+                    help="persist compiled executables in "
+                         "$JAX_COMPILATION_CACHE_DIR (default: .jax_cache "
+                         "in the checkout) so restarts skip recompilation")
     ap.add_argument("--step-timeout", type=float, default=5.0,
                     help="watchdog wall-clock deadline per dispatch (s)")
     ap.add_argument("--hang-grace", type=float, default=1.0,

@@ -18,7 +18,7 @@ from repro.serving.scheduler import (ChunkTask, PrefillProgress, Scheduler,
                                      prompt_buckets, request_rank,
                                      slots_for_hbm)
 from repro.serving.sharding import (assert_cache_placement, cache_shardings,
-                                    place_params, serving_rules)
+                                    init_params, place_params, serving_rules)
 
 __all__ = ["ServingEngine", "DrainBatchEngine", "Request", "CascadeEngine",
            "CascadeServingEngine", "CircuitBreaker",
@@ -34,4 +34,5 @@ __all__ = ["ServingEngine", "DrainBatchEngine", "Request", "CascadeEngine",
            "KVCacheBackend", "RingCache", "PagedCache", "RingLayout",
            "PagedLayout", "RING", "make_backend",
            "enable_compile_cache", "slots_for_hbm", "serving_rules",
-           "place_params", "cache_shardings", "assert_cache_placement"]
+           "init_params", "place_params", "cache_shardings",
+           "assert_cache_placement"]

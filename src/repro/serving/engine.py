@@ -111,6 +111,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import os
+import pathlib
 import time
 from typing import Dict, List, Optional
 
@@ -225,20 +226,29 @@ def validate_prompt(prompt: np.ndarray, max_new_tokens: int,
     return prompt
 
 
-def enable_compile_cache(cache_dir: str) -> None:
-    """Arm JAX's persistent on-disk executable cache under ``cache_dir``.
+# one fixed path inside the checkout: the directory is part of the cache's
+# key, so a path that moved between runs would never hit
+DEFAULT_COMPILE_CACHE = str(
+    pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
 
-    ``warm_compile`` pre-runs every chunk bucket × scan horizon × backend
-    variant per process; with this cache keyed under the serving state dir
-    (``launch/serve.py --compile-cache``), a supervised
-    restart-from-snapshot replays the whole executable family from disk
-    instead of recompiling it — the restarted engine is hot in seconds.
-    Thresholds are dropped to zero so the small CPU-smoke executables are
-    cached too, not just the multi-second TPU compiles."""
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+
+def enable_compile_cache() -> str:
+    """Arm JAX's persistent on-disk executable cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
+    there and no directory is set here; otherwise it goes to
+    ``DEFAULT_COMPILE_CACHE``. ``warm_compile`` pre-runs every chunk bucket
+    × scan horizon × backend variant per process, so a second process (a
+    supervised restart-from-snapshot, a rerun) replays the whole executable
+    family from disk instead of recompiling it. Thresholds are dropped to
+    zero so small executables are cached too, not just the multi-second
+    compiles."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(DEFAULT_COMPILE_CACHE, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
 
 
 class ServingEngine:
@@ -582,13 +592,15 @@ class ServingEngine:
         return len(self._queue)
 
     def warm_compile(self) -> None:
-        """Pre-compile every chunk-program variant and every decode-scan
+        """Pre-compile every admission program (chunk variants, or one
+        monolithic prefill per prompt bucket) and every decode-scan
         horizon. Chunk programs retrace per (chunk bucket × context bucket)
         and the scan per horizon in the scheduler's ``k_schedule`` — small
         static sets — and an XLA compile landing mid-traffic (~1 s) would
-        dominate some request's TTFT (or a multi-K-token stall). Each chunk
-        variant runs once against slot 0 with ``max_new = 0`` and no table
-        row installed; each scan variant runs once with every slot inactive
+        dominate some request's TTFT (or a multi-K-token stall), or trip
+        the gateway's step watchdog. Each admission variant runs once
+        against slot 0 with ``max_new = 0`` and no table row installed;
+        each scan variant runs once with every slot inactive
         — so nothing observable changes (masked appends land out of bounds
         or in the trash block, outputs and positions stay untouched, and
         the junk ``last`` logits are re-armed by any real admission). Call
@@ -614,6 +626,20 @@ class ServingEngine:
                         jnp.int32(1), jnp.int32(0), jnp.int32(1),
                         jnp.int32(0), jnp.float32(0.0), jnp.int32(0),
                         jnp.bool_(False), ctx)
+        else:
+            # monolithic admission, one prefill program per prompt bucket:
+            # each runs into idle slot 0 with max_new = 0 (left inactive)
+            # through an all-(-1) table row, so the paged install lands in
+            # the trash block and the ring line is rewritten whole by the
+            # slot's next admission
+            row = jnp.full((getattr(self.backend, "blocks_per_slot", 1),),
+                           -1, jnp.int32)
+            for bucket in self.buckets:
+                self._cache_state, self._state = self._admit_fn(
+                    self.params, self._cache_state, self._state,
+                    jnp.zeros((1, bucket), jnp.int32), jnp.int32(1),
+                    jnp.int32(0), jnp.int32(0), jnp.float32(0.0),
+                    jnp.int32(0), row)
         if hasattr(self, "_copy_fn"):
             # copying the trash block onto itself is a no-op by definition
             self._cache_state = self._copy_fn(self._cache_state,

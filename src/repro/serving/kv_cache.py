@@ -66,6 +66,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serving.sharding import kv_head_split
+
 
 def _pos1d(cur_pos, batch: int) -> jnp.ndarray:
     return jnp.broadcast_to(jnp.asarray(cur_pos, jnp.int32), (batch,))
@@ -144,7 +146,7 @@ class RingLayout:
         from repro.kernels.ops import decode_attn
         return decode_attn(q, cache["k"], cache["v"], q_pos, cache["pos"],
                            window=window, scale=scale, use_kernel=use_kernel,
-                           interpret=interpret)
+                           interpret=interpret, heads=kv_head_split())
 
     def context(self, cache, block_tables=None) -> Dict[str, jnp.ndarray]:
         """Per-slot contiguous view (identity for the ring)."""
@@ -190,7 +192,7 @@ class PagedLayout:
         return paged_decode_attn(q, cache["k"], cache["v"], q_pos,
                                  cache["pos"], block_tables, window=window,
                                  scale=scale, use_kernel=use_kernel,
-                                 interpret=interpret)
+                                 interpret=interpret, heads=kv_head_split())
 
     def context(self, cache, block_tables=None) -> Dict[str, jnp.ndarray]:
         """Gather each slot's blocks into a contiguous (B, M*bs, ...) view;
@@ -338,10 +340,9 @@ class KVCacheBackend:
         return cache_shardings(mesh, jax.eval_shape(self.init))
 
     def note_placement(self, mesh) -> None:
-        """Record the KV-head split for per-device accounting. Leaves whose
-        KV dim isn't divisible by the split stay replicated — the byte
-        walkers below apply the same per-leaf divisibility rule that
-        ``serving.sharding.cache_pspecs`` uses for placement."""
+        """Record the KV-head split for per-device accounting (placement
+        through ``serving.sharding.cache_pspecs`` refuses a split that
+        does not divide the KV heads)."""
         from repro.serving.sharding import model_axis_size
         self.kv_shards = model_axis_size(mesh)
 
@@ -351,12 +352,11 @@ class KVCacheBackend:
 
 
 def _kv_shard_divisor(path, shape, kv_shards: int) -> int:
-    """Ways a pool leaf's bytes split across devices: K/V leaves with a
-    divisible KV-head dim (dim 3 of 5) split ``kv_shards`` ways, everything
-    else is replicated. Mirrors ``serving.sharding.cache_pspecs``."""
+    """Ways a pool leaf's bytes split across devices: K/V leaves (KV-head
+    dim 3 of 5) split ``kv_shards`` ways, everything else is replicated.
+    Mirrors ``serving.sharding.cache_pspecs``."""
     name = path[-1].key if hasattr(path[-1], "key") else ""
-    if name in ("k", "v") and len(shape) == 5 \
-            and shape[3] % max(kv_shards, 1) == 0:
+    if name in ("k", "v") and len(shape) == 5:
         return max(kv_shards, 1)
     return 1
 

@@ -3,8 +3,9 @@
 What shards, what stays host-global: params split per the decode-mode
 ``launch/sharding_rules`` (attention/KV heads, MLP and vocab projections on
 the mesh's ``model`` axis); KV pools — ring lines ``(L, B, W, KV, hd)`` and
-paged pools ``(L, N, bs, KV, hd)`` — split on the KV-head dim (dim 3) when
-divisible. Everything the host mutates or reasons about stays replicated:
+paged pools ``(L, N, bs, KV, hd)`` — split on the KV-head dim (dim 3) over
+:data:`KV_HEAD_AXIS`; a mesh whose axis does not divide the KV heads is
+refused. Everything the host mutates or reasons about stays replicated:
 block tables, position slots, MLA latent caches (no head dim), the free
 list and commitment ledger (plain Python on the host already).
 
@@ -21,13 +22,27 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from repro.launch import sharding_rules as sr
+from repro.sharding import current_mesh
+
+# the mesh axis that splits KV heads: K/V pools here, and the decode
+# kernels, which run per shard of it (``kernels.ops``)
+KV_HEAD_AXIS = "model"
 
 
 def model_axis_size(mesh: Optional[Mesh]) -> int:
-    """Ways the 'model' mesh axis splits KV heads (1 without a mesh)."""
+    """Ways the KV-head mesh axis splits KV heads (1 without a mesh)."""
     if mesh is None:
         return 1
-    return int(dict(mesh.shape).get("model", 1))
+    return int(dict(mesh.shape).get(KV_HEAD_AXIS, 1))
+
+
+def kv_head_split():
+    """``(mesh, axis)`` the cached-attention kernels split heads over
+    inside the active rules context, or None without a mesh. The pools
+    are placed by :func:`cache_pspecs`, which refuses a mesh whose axis
+    does not divide the KV heads, so the split always divides."""
+    mesh = current_mesh()
+    return None if mesh is None else (mesh, KV_HEAD_AXIS)
 
 
 def serving_rules(mesh: Mesh):
@@ -41,6 +56,15 @@ def param_shardings(mesh: Mesh, lm):
     abstract, axes = lm.abstract()
     return sr.named(mesh, sr.param_pspecs(mesh, abstract, axes,
                                           mode="decode"))
+
+
+def init_params(lm, rng, mesh: Optional[Mesh] = None):
+    """``lm``'s params from ``rng``, as one jitted program. With a mesh its
+    outputs land straight in their decode shardings, so no device ever
+    holds the whole tree (a model that needs the mesh could not be built
+    on one chip first); without one, on the default device."""
+    kw = {} if mesh is None else {"out_shardings": param_shardings(mesh, lm)}
+    return jax.jit(lambda r: lm.init(r)[0], **kw)(rng)
 
 
 def place_params(mesh: Mesh, lm, params):
@@ -59,14 +83,23 @@ def _kv_pool_leaf(path, leaf) -> bool:
 
 def cache_pspecs(mesh: Mesh, cache_state):
     """PartitionSpec tree matching a serving cache state pytree: K/V pool
-    leaves split dim 3 (KV heads) on 'model' when divisible, everything
-    else — tables, pos, latents — replicated (host-global semantics)."""
+    leaves split dim 3 (KV heads) on :data:`KV_HEAD_AXIS`, everything
+    else — tables, pos, latents — replicated (host-global semantics).
+    Raises ValueError when the axis does not divide a pool's KV heads:
+    replicating that pool would gather it onto every device, and the
+    decode kernels could not run per shard."""
     msize = model_axis_size(mesh)
 
     def spec(path, leaf):
         dims = [None] * leaf.ndim
-        if _kv_pool_leaf(path, leaf) and leaf.shape[3] % msize == 0:
-            dims[3] = "model"
+        if _kv_pool_leaf(path, leaf):
+            if leaf.shape[3] % msize:
+                raise ValueError(
+                    f"mesh axis {KV_HEAD_AXIS!r} of size {msize} does not "
+                    f"divide the {leaf.shape[3]} KV heads of cache leaf "
+                    f"{jax.tree_util.keystr(path)}; use a {KV_HEAD_AXIS} "
+                    f"axis that divides them")
+            dims[3] = KV_HEAD_AXIS
         return PS(*dims)
 
     return jax.tree_util.tree_map_with_path(spec, cache_state)

@@ -80,6 +80,12 @@ def resolve(rules: Dict[str, object], axes: Sequence[Optional[str]],
     return PS(*spec)
 
 
+def current_mesh() -> Optional[Mesh]:
+    """The mesh of the active :func:`use_rules` context (None outside)."""
+    ctx = _current()
+    return None if ctx is None else ctx[0]
+
+
 def hint(x, axes: Sequence[Optional[str]]):
     """Constrain activation sharding if a mesh context is active."""
     ctx = _current()

@@ -15,16 +15,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
-try:                             # moved to the jax top level in newer jax
-    from jax import shard_map
-except ImportError:              # pragma: no cover - jax<0.5 fallback
-    from jax.experimental.shard_map import shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma
-import inspect as _inspect
-_SM_CHECK = ({"check_vma": False}
-             if "check_vma" in _inspect.signature(shard_map).parameters
-             else {"check_rep": False})
 
 from repro.optim import sgd_init, sgd_update
 
@@ -73,11 +63,11 @@ class FederatedTrainer:
                         mean_loss[None])
 
             spec_leading = PS(self.axis)
-            return shard_map(
+            return jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(spec_leading, spec_leading, spec_leading),
                 out_specs=(spec_leading, spec_leading, spec_leading),
-                **_SM_CHECK,
+                check_vma=False,
             )(params, opt, batch)
 
         return jax.jit(fed_round)

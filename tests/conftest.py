@@ -1,37 +1,9 @@
 import os
 import sys
-import types
 
 # src-layout import path (tests run as PYTHONPATH=src pytest tests/)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
-import pytest
 
 jax.config.update("jax_enable_x64", False)
-
-# Optional-import shim: hypothesis only drives the property tests. When it's
-# absent, install a stub so the modules still collect — @given tests become
-# skips instead of collection errors for the whole module.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    def _given(*_a, **_k):
-        def deco(fn):
-            return pytest.mark.skip(
-                reason="hypothesis not installed")(fn)
-        return deco
-
-    def _settings(*_a, **_k):
-        return lambda fn: fn
-
-    class _Strategies(types.ModuleType):
-        def __getattr__(self, name):            # st.integers(...), etc.
-            return lambda *a, **k: None
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _Strategies("hypothesis.strategies")
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _hyp.strategies

@@ -27,7 +27,8 @@ SCRIPT = textwrap.dedent("""
     from repro.configs.shapes import InputShape
     from repro.models.model import LM
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_config("smollm-135m").reduced()
     lm = LM(cfg, kv_chunk=16)
     shape = InputShape("t", seq_len=32, global_batch=8, mode="train")
@@ -45,8 +46,6 @@ SCRIPT = textwrap.dedent("""
             lowered = jitted.lower(*abstract_in)
         compiled = lowered.compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):          # older jax returns [dict]
-        cost = cost[0]
     coll = collective_bytes(compiled.as_text())
     print(json.dumps({
         "flops": cost.get("flops"),

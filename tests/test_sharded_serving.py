@@ -5,7 +5,13 @@ device_count`` must be set before ``import jax``; conftest already imported
 it): ring/paged × greedy/sampled × multi-step × speculative engines on a
 4-device mesh must stream token-for-token identically to the single-device
 engine on the same trace — faults included — and snapshots taken on a mesh
-must restore token-exact both onto a mesh and onto ``mesh=None``.
+must restore token-exact both onto a mesh and onto ``mesh=None``. The
+decode kernels, forced on in interpret mode, must match too when they run
+per KV-head shard inside ``shard_map``.
+
+A second, smaller subprocess pins placement: ``init_params`` on a mesh
+equals the single-device init, and a mesh whose ``model`` axis does not
+divide the KV heads is refused.
 
 The in-process test pins the other half of the contract: ``mesh=None``
 compiles exactly the warm executable set (no new variants post-warm), so
@@ -108,20 +114,107 @@ SCRIPT = textwrap.dedent("""
         and kv.hbm_bytes_per_device() < kv.hbm_bytes()
         and kv.block_bytes_per_device() * kv.num_blocks
         == kv.hbm_bytes_per_device())
+
+    # on a TPU the decode kernels run per KV-head shard inside shard_map:
+    # take that path here (kernels in interpret mode), held to mesh=None
+    from repro.kernels import decode_attention as da
+    from repro.kernels import ops
+    ops._on_tpu = lambda: True
+    ops._da_kernel = lambda *a, interpret=None, **k: \
+        da.decode_attention(*a, interpret=True, **k)
+    ops._pda_kernel = lambda *a, interpret=None, **k: \
+        da.paged_decode_attention(*a, interpret=True, **k)
+    for backend in ("ring", "paged"):
+        results[f"kernel_per_shard_{backend}"] = \
+            run(mk(None, backend)) == run(mk(MESH, backend))
     print(json.dumps(results))
 """)
 
 
-@pytest.mark.slow
-def test_sharded_serving_exactness_matrix():
+PLACEMENT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    import json
+    import jax
+    import numpy as np
+
+    from repro.configs.base import ModelConfig, dense_stages
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.model import LM
+    from repro.serving import ServingEngine, init_params
+    from repro.serving.sharding import param_shardings
+
+    def model(kv):
+        return LM(ModelConfig(
+            name="place-test", family="dense", source="test", num_layers=2,
+            d_model=64, num_heads=4, num_kv_heads=kv, head_dim=16,
+            d_ff=128, vocab_size=256, stages=dense_stages(2),
+            param_dtype="bfloat16"), kv_chunk=32)
+
+    MESH = make_host_mesh(model=4)
+    RNG = jax.random.PRNGKey(0)
+    lm = model(4)
+    single = jax.tree.leaves(init_params(lm, RNG))
+    sharded = jax.tree.leaves(init_params(lm, RNG, MESH))
+    want = jax.tree.leaves(param_shardings(MESH, lm))
+    results = {
+        "init_equal": all(np.array_equal(np.asarray(a), np.asarray(b))
+                          for a, b in zip(single, sharded)),
+        "init_placed": len(sharded) == len(want) and all(
+            x.sharding.is_equivalent_to(w, x.ndim)
+            for x, w in zip(sharded, want)),
+        "init_split": any(x.sharding.shard_shape(x.shape) != x.shape
+                          for x in sharded),
+    }
+    # 2 KV heads on a 4-way axis: the engine refuses the mesh
+    lm2 = model(2)
+    p2 = init_params(lm2, RNG)
+    for backend in ("ring", "paged"):
+        try:
+            ServingEngine(lm2, p2, batch_slots=2, max_seq_len=32,
+                          cache_backend=backend, mesh=MESH)
+            results[f"refuses_{backend}"] = False
+        except ValueError as e:
+            results[f"refuses_{backend}"] = "does not divide" in str(e)
+    print(json.dumps(results))
+""")
+
+
+def _run_forced_devices(script: str) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
+    out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.slow
+def test_sharded_serving_exactness_matrix():
+    rec = _run_forced_devices(SCRIPT)
     assert all(rec.values()), rec
+
+
+@pytest.fixture(scope="module")
+def placement():
+    return _run_forced_devices(PLACEMENT)
+
+
+def test_init_params_mesh_matches_single_device(placement):
+    """One jitted init: on a mesh its leaves land in their decode
+    shardings (some split) and equal the no-mesh init bit for bit."""
+    assert placement["init_equal"], placement
+    assert placement["init_placed"], placement
+    assert placement["init_split"], placement
+
+
+@pytest.mark.parametrize("backend", ["ring", "paged"])
+def test_mesh_refuses_indivisible_kv_heads(placement, backend):
+    """A model axis that does not divide the KV heads is refused at
+    construction, not served from pools replicated on every device."""
+    assert placement[f"refuses_{backend}"], placement
 
 
 def test_mesh_none_executable_set_unchanged():
@@ -147,10 +240,10 @@ def test_mesh_none_executable_set_unchanged():
     assert eng.mesh is None and eng.rules is None
     eng.warm_compile()
     assert eng.warm_compile_s is not None and eng.warm_compile_s > 0
-    # decode executables close at warm_compile (admission lawfully
-    # retraces per prompt bucket — pre-existing monolithic behavior)
+    # admission and decode executables close at warm_compile
     counts = {name: getattr(eng, name)._cache_size()
-              for name in ("_step_fn", "_scan_fn")}
+              for name in ("_admit_fn", "_step_fn", "_scan_fn")}
+    assert counts["_admit_fn"] == len(eng.buckets)
     rng = np.random.default_rng(0)
     for i in range(3):
         eng.submit(rng.integers(0, 128, size=4 + i), max_new_tokens=4,

@@ -78,7 +78,8 @@ def test_hint_noop_without_context():
 
 def test_hint_applies_constraint_under_mesh():
     import jax.numpy as jnp
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with sh.use_rules(mesh, {sh.BATCH: ("data",)}):
         y = sh.hint(jnp.ones((4, 8)), (sh.BATCH, None))
     assert y.shape == (4, 8)
