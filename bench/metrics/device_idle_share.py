@@ -1,0 +1,9 @@
+"""device_idle_share: 1 - union of device operation intervals over the
+traced window, in %."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
