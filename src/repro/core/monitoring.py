@@ -4,8 +4,7 @@ in-app controllers (the AP policy reads EIL estimates from here).
 """
 from __future__ import annotations
 
-import statistics
-from typing import Dict, List, Optional
+from typing import Dict, Optional
 
 from repro.utils.logging import EventLog
 
@@ -15,18 +14,6 @@ class MonitoringService(EventLog):
         super().__init__(name="ace-monitor")
 
     # -- metric helpers --------------------------------------------------------
-    def record_latency(self, component: str, latency_s: float, **fields):
-        self.log("latency", component=component, latency_s=latency_s, **fields)
-
-    def latency_stats(self, component: str,
-                      since: float = 0.0) -> Optional[dict]:
-        vals = [e["latency_s"] for e in self.query("latency", component=component)
-                if e["t"] >= since]
-        if not vals:
-            return None
-        return {"n": len(vals), "mean": statistics.fmean(vals),
-                "p50": statistics.median(vals), "max": max(vals)}
-
     def counters(self, kind: str) -> int:
         return len(self.query(kind))
 

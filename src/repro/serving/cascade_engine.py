@@ -123,7 +123,6 @@ class CascadeRequest:
     priority: int = 0                # SLO class, forwarded to the routed engine
     deadline_s: Optional[float] = None   # relative to *cascade* submit time
     submit_s: float = 0.0
-    enqueue_s: float = 0.0           # cascade-queue entry (gateway forward)
     output: Optional[np.ndarray] = None
     ttft_s: float = 0.0              # from cascade submit (gate wait included)
     finish_s: float = 0.0
@@ -267,7 +266,6 @@ class CascadeServingEngine:
         deadline budgets are already shrunk by gate wait), so this never
         refuses; ``ahead_extra`` is accepted for protocol parity."""
         del ahead_extra
-        r.enqueue_s = time.perf_counter()
         self._requests.append(r)
 
     def queue_depth(self) -> int:
@@ -589,7 +587,6 @@ class CascadeServingEngine:
                                priority=int(meta["priority"]),
                                deadline_s=meta["deadline_s"])
             r.submit_s = now - float(meta["age_s"])
-            r.enqueue_s = now
             r.ttft_s = float(meta["ttft_s"])
             r._gen = (int(meta["max_new_tokens"]),
                       float(meta["temperature"]))
@@ -635,7 +632,6 @@ class CascadeServingEngine:
         r = CascadeRequest(int(request_id), prompt, priority=priority,
                            deadline_s=deadline_s)
         r.submit_s = time.perf_counter()
-        r.enqueue_s = r.submit_s
         r._gen = (max_new_tokens, temperature)
         self._next_id = max(self._next_id, int(request_id) + 1)
         self._requests.append(r)

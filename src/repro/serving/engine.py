@@ -125,6 +125,7 @@ from repro.models.model import LM
 from repro.serving.faults import FaultError, FaultPlan
 from repro.serving.kv_cache import (RingCache, RingLayout, make_backend,
                                     resolve_swap_caches)
+from repro.serving import tracing
 from repro.serving.sharding import (assert_cache_placement, cache_shardings,
                                     place_params, serving_rules)
 from repro.serving.sampler import (accepted_prefix_length, request_keys,
@@ -165,12 +166,6 @@ class Request:
     not_before_step: int = 0     # backoff: ineligible before this step
     fault_s: float = 0.0         # wall-clock of last fault requeue (recovery
     #                              latency = next slot grant - fault_s)
-    enqueue_s: float = 0.0       # wall-clock at *engine* queue entry. Equal
-    #                              to submit_s on the direct submit() path;
-    #                              later when a gateway held the request in
-    #                              its bounded queue first — latency/TTFT are
-    #                              always measured from submit_s (the service
-    #                              boundary), never from here
 
 
 @dataclasses.dataclass
@@ -312,9 +307,8 @@ class ServingEngine:
         # headroom (and the look-ahead reservation its positions) without
         # an extra device pull
         self._scanned: Dict[int, int] = {}
-        # perf counters (dispatch/occupancy/sharing for bench_serving):
-        # decode_steps counts *token* rounds (a K-scan adds K), host_syncs
-        # counts active-mask transfers (a K-scan adds 1)
+        # counters: decode_steps counts *token* rounds (a K-scan adds K),
+        # host_syncs counts active-mask transfers (a K-scan adds 1)
         self.decode_steps = 0
         self.host_syncs = 0
         self.generated_tokens = 0
@@ -584,7 +578,6 @@ class ServingEngine:
                     return
                 r.deadline_s = None          # downgrade: serve best-effort
                 r.downgraded = True
-        r.enqueue_s = time.perf_counter()
         self._queue.append(r)
 
     def queue_depth(self) -> int:
@@ -693,7 +686,13 @@ class ServingEngine:
         """Execute one scheduler plan: admissions and prompt chunks first,
         then the decode round. Public so drivers can interleave arrivals
         with serving (see ``benchmarks/bench_serving.py``); ``run`` is just
-        this in a drain loop."""
+        this in a drain loop. Timed as the ``engine.step`` span, whose
+        record carries the KV gauges (``kv_gauges``)."""
+        with tracing.span(tracing.STEP) as sp:
+            self._step()
+            sp.gauges = self.kv_gauges()
+
+    def _step(self) -> None:
         self._step_count += 1
         slots, free, prefilling = self._slots, self._free, self._prefilling
         if self._faults is not None and self._faults.fire("cancel"):
@@ -707,13 +706,16 @@ class ServingEngine:
         min_headroom = min(
             (r.max_new_tokens - self._scanned.get(s, 0)
              for s, r in slots.items()), default=None)
-        plan = self.scheduler.plan_step(
-            n_active=len(slots), prefilling=prefilling,
-            try_admit=lambda: self._try_admit(slots, free, prefilling),
-            min_headroom=min_headroom,
-            try_preempt=lambda: self._try_preempt(slots))
+        with tracing.span("engine.plan"):
+            plan = self.scheduler.plan_step(
+                n_active=len(slots), prefilling=prefilling,
+                try_admit=lambda: self._try_admit(slots, free, prefilling),
+                min_headroom=min_headroom,
+                try_preempt=lambda: self._try_preempt(slots))
         for c in plan.chunks:
-            self._run_chunk(c, prefilling, slots)
+            with tracing.span("engine.chunk",
+                              prefilling[c.slot].request.request_id):
+                self._run_chunk(c, prefilling, slots)
         if self._pending_swaps:
             # rollback-path swap-outs started their D2H copies
             # asynchronously; the planning/chunk work above overlapped
@@ -1067,13 +1069,14 @@ class ServingEngine:
                 r.resume.kv = None
                 self._record_retry(r, "swap_in")
                 return None
-            self._queue.remove(r)
-            slot = free.pop()
-            self._cache_state = self.backend.swap_in(
-                self._cache_state, slot, r.resume.kv, len(r.prompt),
-                r.max_new_tokens)
-            self._note_grant(r)
-            self._arm_resumed(r, slot, slots)
+            with tracing.span("engine.admit", r.request_id):
+                self._queue.remove(r)
+                slot = free.pop()
+                self._cache_state = self.backend.swap_in(
+                    self._cache_state, slot, r.resume.kv, len(r.prompt),
+                    r.max_new_tokens)
+                self._note_grant(r)
+                self._arm_resumed(r, slot, slots)
             return MONOLITHIC
         # fresh admission, or recompute-resume (re-prefill prompt + already
         # generated tokens; the decode checkpoint is restored at arming)
@@ -1084,6 +1087,15 @@ class ServingEngine:
             else len(tokens)
         if not self.backend.can_admit(key, remaining):
             return None
+        with tracing.span("engine.admit", r.request_id):
+            return self._grant(r, tokens, key, remaining, slots, free,
+                               prefilling)
+
+    def _grant(self, r: Request, tokens: np.ndarray, key, remaining: int,
+               slots, free, prefilling):
+        """Admit ``r`` (fresh, or resumed by recompute) into a free slot:
+        a monolithic prefill, or the slot's blocks and table row ahead of
+        its prompt chunks."""
         self._queue.remove(r)
         slot = free.pop()
         if not self.scheduler.chunked:
@@ -1425,6 +1437,8 @@ class ServingEngine:
             "hang_recoveries": self.hang_recoveries,
             "warm_compile_s": self.warm_compile_s,
             "mesh_devices": self.mesh.size if self.mesh is not None else 1,
+            # {span: {"count", "s"}} over the process (repro.serving.tracing)
+            "host_phases": tracing.totals(),
         }
 
     def speculative_metrics(self) -> Dict[str, object]:
@@ -1521,7 +1535,8 @@ class ServingEngine:
     def _decode_round(self, slots, free, done, k: int = 1):
         if not slots:
             return
-        self._reserve_lookahead(slots, k)
+        with tracing.span("engine.reserve"):
+            self._reserve_lookahead(slots, k)
         if self._faults is not None:
             if self._faults.fire("hang"):
                 # a hung dispatch: stall without raising — no exception
@@ -1536,13 +1551,15 @@ class ServingEngine:
             # the ordinary free/swap path)
             self._faults.check("scan" if k > 1 else "step",
                                f"decode round over {len(slots)} slots")
-        if k == 1:
-            self._cache_state, self._state = self._step_fn(
-                self.params, self._cache_state, self._state, self._base_key)
-        else:
-            self._cache_state, self._state = self._scan_fn(
-                self.params, self._cache_state, self._state, self._base_key,
-                k)
+        with tracing.span("engine.dispatch"):
+            if k == 1:
+                self._cache_state, self._state = self._step_fn(
+                    self.params, self._cache_state, self._state,
+                    self._base_key)
+            else:
+                self._cache_state, self._state = self._scan_fn(
+                    self.params, self._cache_state, self._state,
+                    self._base_key, k)
         self.decode_steps += k
         self.host_syncs += 1
         self.planned_token_slots += len(slots) * k
@@ -1552,7 +1569,9 @@ class ServingEngine:
             # the draft cache saw none of this round's tokens: mark the
             # slots so the next speculative round re-syncs them first
             self._draft_dirty.update(slots.keys())
-        self._finish_round(slots, free, done)
+        with tracing.span("engine.sync"):
+            active = np.asarray(self._state["active"])   # the one host sync
+        self._finish_round(slots, free, done, active)
 
     def _spec_round(self, slots, free, done, k: int):
         """One speculative propose-k/verify round (see ``_spec_impl``).
@@ -1567,15 +1586,21 @@ class ServingEngine:
             self._faults.check(
                 "draft", f"speculative round over {len(slots)} slots, k={k}")
         self._resync_draft(slots)
-        self._reserve_lookahead(slots, k + 1)
+        with tracing.span("engine.reserve"):
+            self._reserve_lookahead(slots, k + 1)
         before = dict(self._scanned)
-        self._cache_state, self._draft_state, self._state = self._spec_fn(
-            self.params, self.draft_params, self._cache_state,
-            self._draft_state, self._state, self._base_key, k)
+        with tracing.span("engine.dispatch"):
+            (self._cache_state, self._draft_state,
+             self._state) = self._spec_fn(
+                self.params, self.draft_params, self._cache_state,
+                self._draft_state, self._state, self._base_key, k)
         self.host_syncs += 1
         self.planned_token_slots += len(slots) * (k + 1)
         self.spec_rounds += 1
-        steps_h = np.asarray(self._state["steps"])
+        with tracing.span("engine.sync"):
+            active = np.asarray(self._state["active"])   # the one host sync
+        with tracing.span("engine.pull"):
+            steps_h = np.asarray(self._state["steps"])
         accepted_total = 0
         for slot, r in slots.items():
             committed = int(steps_h[slot]) - before[slot]
@@ -1591,7 +1616,7 @@ class ServingEngine:
             self._spec_class[r.priority] = (d + k, a + acc)
         self.scheduler.observe_speculation(len(slots), len(slots) * k,
                                            accepted_total)
-        self._finish_round(slots, free, done, steps_h=steps_h)
+        self._finish_round(slots, free, done, active, steps_h=steps_h)
 
     def _resync_draft(self, slots) -> None:
         """Rebuild the draft cache for slots that advanced through plain
@@ -1624,65 +1649,84 @@ class ServingEngine:
             jnp.int32(length), jnp.int32(slot))
         self._draft_dirty.discard(slot)
 
-    def _finish_round(self, slots, free, done, steps_h=None, out_h=None):
-        """Post-dispatch bookkeeping shared by plain and speculative
-        decode rounds: TTFT stamps, the stream tap, completion handling.
-        Device reads are whole-array pulls sliced host-side — an eager
-        per-completion ``state["out"][slot, :n]`` compiles a fresh tiny
-        executable per (slot, n) shape, which is exactly the cold-probe
-        capacity cost the open-loop bench used to dodge with a throwaway
-        warm pass."""
-        active = np.asarray(self._state["active"])       # the one host sync
+    def _finish_round(self, slots, free, done, active, steps_h=None):
+        """Post-sync bookkeeping shared by plain and speculative decode
+        rounds (``active`` is the mask the round's sync pulled): TTFT
+        stamps, the stream tap, completion handling. Device reads are
+        whole-array pulls sliced host-side — an eager per-completion
+        ``state["out"][slot, :n]`` compiles a fresh tiny executable per
+        (slot, n) shape, which is exactly the cold-probe capacity cost the
+        open-loop bench used to dodge with a throwaway warm pass."""
         now = time.perf_counter()
-        for r in slots.values():
-            # every budget>0 member banked >= 1 token in the round above;
-            # budget-0 requests never produce one and get no TTFT
-            if r.ttft_s == 0.0 and r.max_new_tokens > 0:
-                r.ttft_s = now - r.submit_s
         finished = [s for s in slots if not active[s]]
-        if self.on_tokens is not None or finished:
-            if steps_h is None:
-                steps_h = np.asarray(self._state["steps"])
-            if out_h is None:
+        with tracing.span("engine.pull"):
+            for r in slots.values():
+                # every budget>0 member banked >= 1 token in the round
+                # above; budget-0 requests never produce one and get no TTFT
+                if r.ttft_s == 0.0 and r.max_new_tokens > 0:
+                    r.ttft_s = now - r.submit_s
+            if self.on_tokens is not None or finished:
+                if steps_h is None:
+                    steps_h = np.asarray(self._state["steps"])
                 out_h = np.asarray(self._state["out"])
-        if self.on_tokens is not None:
-            # stream tap: surface this round's new tokens per live request
-            # (the host sync above already landed, so the arrays are final
-            # for the round; a row that finished mid-round stopped at its
-            # true step count). Rides the same sync — no extra round-trip
-            # boundary, just two host pulls the gateway opted into.
-            events = []
-            for slot, r in slots.items():
+            if self.on_tokens is not None:
+                # stream tap: surface this round's new tokens per live
+                # request (the host sync already landed, so the arrays are
+                # final for the round; a row that finished mid-round stopped
+                # at its true step count). Rides the same sync — no extra
+                # round-trip boundary, just two host pulls the gateway
+                # opted into.
+                events = []
+                for slot, r in slots.items():
+                    n = int(steps_h[slot])
+                    seen = self._emitted.get(r.request_id, 0)
+                    if n > seen:
+                        events.append((r.request_id,
+                                       np.array(out_h[slot, seen:n])))
+                        self._emitted[r.request_id] = n
+                if events:
+                    self.on_tokens(events)
+        with tracing.span("engine.finish"):
+            for slot in finished:
+                r = slots.pop(slot)
+                self._scanned.pop(slot, None)
+                self._emitted.pop(r.request_id, None)
+                if self.speculative:
+                    self._draft_dirty.discard(slot)
                 n = int(steps_h[slot])
-                seen = self._emitted.get(r.request_id, 0)
-                if n > seen:
-                    events.append((r.request_id,
-                                   np.array(out_h[slot, seen:n])))
-                    self._emitted[r.request_id] = n
-            if events:
-                self.on_tokens(events)
-        for slot in finished:
-            r = slots.pop(slot)
-            self._scanned.pop(slot, None)
-            self._emitted.pop(r.request_id, None)
-            if self.speculative:
-                self._draft_dirty.discard(slot)
-            n = int(steps_h[slot])
-            r.output = np.array(out_h[slot, :n])
-            r.status = "done"
-            r.finish_s = time.perf_counter()
-            r.latency_s = r.finish_s - r.submit_s
-            self.generated_tokens += n
-            self._status_counts["done"] += 1
-            self.scheduler.observe_service(r.priority,
-                                           r.finish_s - r.admit_s)
-            if r.deadline_s is not None:
-                self.scheduler.observe_deadline(
-                    r.priority, r.latency_s <= r.deadline_s)
-            self._cache_state = self.backend.free_slot(self._cache_state,
-                                                       slot)
-            free.append(slot)
-            done[r.request_id] = r
+                r.output = np.array(out_h[slot, :n])
+                r.status = "done"
+                r.finish_s = time.perf_counter()
+                r.latency_s = r.finish_s - r.submit_s
+                self.generated_tokens += n
+                self._status_counts["done"] += 1
+                self.scheduler.observe_service(r.priority,
+                                               r.finish_s - r.admit_s)
+                if r.deadline_s is not None:
+                    self.scheduler.observe_deadline(
+                        r.priority, r.latency_s <= r.deadline_s)
+                self._cache_state = self.backend.free_slot(
+                    self._cache_state, slot)
+                free.append(slot)
+                done[r.request_id] = r
+
+    def kv_gauges(self) -> Dict[str, int]:
+        """The KV allocator's gauges, stored on each ``engine.step`` span:
+        ``kv_blocks_committed`` (blocks in use plus blocks promised to
+        admitted requests and not yet drawn), ``kv_blocks_live`` (blocks
+        holding written tokens, summed over decoding and prefilling
+        slots) and ``slots_decoding``. The block gauges read 0 on the ring
+        backend, which has no blocks."""
+        b = self.backend
+        committed = live = 0
+        if hasattr(b, "blocks_committed"):
+            bs = b.block_size
+            committed = b.blocks_committed
+            live = sum(-(-(len(r.prompt) + self._scanned[s]) // bs)
+                       for s, r in self._slots.items())
+            live += sum(-(-pp.next // bs) for pp in self._prefilling.values())
+        return {"kv_blocks_committed": committed, "kv_blocks_live": live,
+                "slots_decoding": len(self._slots)}
 
     # -- stats ----------------------------------------------------------------
     def occupancy(self) -> float:
@@ -1922,7 +1966,6 @@ class ServingEngine:
                             else np.zeros((0,), np.int32)),
                     last=np.asarray(rec["last"], np.float32),
                     kv=kv)
-            r.enqueue_s = now
             self._queue.append(r)
             live += 1
         self._queue.sort(key=request_rank)
@@ -1947,7 +1990,6 @@ class ServingEngine:
         r = Request(int(request_id), prompt, max_new_tokens, temperature,
                     priority=priority, deadline_s=deadline_s)
         r.submit_s = time.perf_counter()
-        r.enqueue_s = r.submit_s
         self._next_id = max(self._next_id, int(request_id) + 1)
         self._queue.append(r)
         return r

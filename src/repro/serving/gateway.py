@@ -72,6 +72,7 @@ import numpy as np
 from .engine import load_snapshot, save_snapshot
 from .journal import RequestJournal
 from .scheduler import request_rank
+from . import tracing
 
 _DONE = object()        # stream sentinel: the handle reached a terminal state
 
@@ -388,10 +389,13 @@ class ServingGateway:
                 "hang_recoveries": m.get("hang_recoveries", 0),
                 "edge": {k: m["edge"].get(k, 0) for k in keys},
                 "cloud": {k: m["cloud"].get(k, 0) for k in keys},
+                # process-wide: both legs' spans share one recorder
+                "host_phases": m["edge"]["host_phases"],
             }
         elif callable(getattr(eng, "metrics", None)):
             m = eng.metrics()
             s["engine"] = {k: m.get(k, 0) for k in keys}
+            s["engine"]["host_phases"] = m["host_phases"]
         return s
 
     # -- internals (loop thread unless noted) ---------------------------------
@@ -509,38 +513,45 @@ class ServingGateway:
                 # trips on it would roll back (or declare wedged) a
                 # perfectly healthy engine at startup
                 await loop.run_in_executor(None, eng.warm_compile)
+            stepped = False
             while True:
-                # cancels first: the engine is idle on this thread
-                # between steps, so these apply atomically
-                cancels, self._cancels = self._cancels, []
-                for rid, fut in cancels:
-                    ok = eng.cancel(rid)
-                    if not fut.done():
-                        fut.set_result(ok)
-                # forward inbox -> engine while its queue is shallow;
-                # admission control prices the better-ranked gateway
-                # tail via ahead_extra
-                forwarded = False
-                while (self._inbox
-                       and eng.queue_depth() < self.forward_depth):
-                    r = self._inbox.popleft()
-                    mine = request_rank(r)
-                    ahead = sum(1 for q in self._inbox
-                                if request_rank(q) <= mine)
-                    eng.enqueue(r, ahead_extra=ahead)
-                    forwarded = True
-                if forwarded:
-                    async with self._room:
-                        self._room.notify_all()
-                self._resolve(eng.take_done())
-                if eng.pending:
-                    await self._step_watched(loop, eng)
-                    self._dispatch_taps()
+                # one gateway.loop span per gap between engine steps
+                with tracing.span("gateway.loop"):
+                    if stepped:
+                        self._dispatch_taps()
+                        self._resolve(eng.take_done())
+                        self.steps_driven += 1
+                        if (self.snapshot_dir is not None
+                                and self.snapshot_every
+                                and self.steps_driven
+                                % self.snapshot_every == 0):
+                            self._checkpoint()
+                    # cancels first: the engine is idle on this thread
+                    # between steps, so these apply atomically
+                    cancels, self._cancels = self._cancels, []
+                    for rid, fut in cancels:
+                        ok = eng.cancel(rid)
+                        if not fut.done():
+                            fut.set_result(ok)
+                    # forward inbox -> engine while its queue is shallow;
+                    # admission control prices the better-ranked gateway
+                    # tail via ahead_extra
+                    forwarded = False
+                    while (self._inbox
+                           and eng.queue_depth() < self.forward_depth):
+                        r = self._inbox.popleft()
+                        mine = request_rank(r)
+                        ahead = sum(1 for q in self._inbox
+                                    if request_rank(q) <= mine)
+                        eng.enqueue(r, ahead_extra=ahead)
+                        forwarded = True
+                    if forwarded:
+                        async with self._room:
+                            self._room.notify_all()
                     self._resolve(eng.take_done())
-                    self.steps_driven += 1
-                    if (self.snapshot_dir is not None and self.snapshot_every
-                            and self.steps_driven % self.snapshot_every == 0):
-                        self._checkpoint()
+                stepped = eng.pending
+                if stepped:
+                    await self._step_watched(loop, eng)
                     continue
                 if self._inbox or self._cancels:
                     continue

@@ -860,6 +860,12 @@ class PagedCache(KVCacheBackend):
         return (self.num_blocks - 1) - len(self._free_plain) \
             - len(self._free_cached)
 
+    @property
+    def blocks_committed(self) -> int:
+        """Blocks in use plus those admission promised to live slots and
+        ``reserve_lookahead`` has not drawn yet."""
+        return self.blocks_in_use + self._gap_total
+
     def reset_stats(self) -> None:
         """Zero the admission accounting (e.g. after bench warm-up) so
         ``hbm_bytes_per_slot`` averages only the measured traffic."""

@@ -1,7 +1,6 @@
 """Minimal structured logging for platform events and benchmarks."""
 from __future__ import annotations
 
-import json
 import sys
 import time
 from typing import Any, Dict, List, Optional
@@ -32,7 +31,3 @@ class EventLog:
                 out.append(ev)
         return out
 
-    def dump(self, path: str) -> None:
-        with open(path, "w") as f:
-            for ev in self.events:
-                f.write(json.dumps(ev) + "\n")
