@@ -121,6 +121,7 @@ import numpy as np
 
 from repro.checkpoint.io import (json_leaf, json_unleaf,
                                  load_checkpoint_tree, save_checkpoint)
+from repro.kernels.decode_attention import live_pages
 from repro.models.model import LM
 from repro.serving.faults import FaultError, FaultPlan
 from repro.serving.kv_cache import (RingCache, RingLayout, make_backend,
@@ -1715,18 +1716,26 @@ class ServingEngine:
         ``kv_blocks_committed`` (blocks in use plus blocks promised to
         admitted requests and not yet drawn), ``kv_blocks_live`` (blocks
         holding written tokens, summed over decoding and prefilling
-        slots) and ``slots_decoding``. The block gauges read 0 on the ring
-        backend, which has no blocks."""
+        slots), ``slots_decoding``, and ``attn_pages_visited``: the table
+        entries the paged attention kernel visits for the decoding slots'
+        latest tokens in a layer without a window (``live_pages``), out of
+        ``batch_slots`` × the table width. The block gauges read 0 on the
+        ring backend, which has no blocks."""
         b = self.backend
-        committed = live = 0
+        committed = live = visited = 0
         if hasattr(b, "blocks_committed"):
             bs = b.block_size
             committed = b.blocks_committed
             live = sum(-(-(len(r.prompt) + self._scanned[s]) // bs)
                        for s, r in self._slots.items())
             live += sum(-(-pp.next // bs) for pp in self._prefilling.values())
+            last = np.array([len(r.prompt) + self._scanned[s] - 1
+                             for s, r in self._slots.items()], np.int64)
+            lo, hi = live_pages(last, last, bs, b.blocks_per_slot, xp=np)
+            visited = int(np.sum(hi - lo))
         return {"kv_blocks_committed": committed, "kv_blocks_live": live,
-                "slots_decoding": len(self._slots)}
+                "slots_decoding": len(self._slots),
+                "attn_pages_visited": visited}
 
     # -- stats ----------------------------------------------------------------
     def occupancy(self) -> float:

@@ -18,8 +18,9 @@ Span names (fixed: ``bench/metrics`` and PERF.md read them by name):
 
 ==================  ========================================================
 ``engine.step``     all of ``ServingEngine.step``; its record carries the
-                    gauges ``kv_blocks_committed``, ``kv_blocks_live`` and
-                    ``slots_decoding``, sampled as the step ends
+                    gauges ``kv_blocks_committed``, ``kv_blocks_live``,
+                    ``slots_decoding`` and ``attn_pages_visited``, sampled
+                    as the step ends
 ``engine.plan``     ``Scheduler.plan_step`` with its admission and
                     preemption callbacks
 ``engine.admit``    one granted admission: the allocator, copy-on-write

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import decode_attention as dattn
 from repro.kernels import ref
 from repro.kernels.decode_attention import paged_decode_attention
 
@@ -21,15 +22,19 @@ CASES = [
 
 
 def _paged_cache(rng, kv, hd, bs, fills, *, dtype=jnp.float32,
-                 scatter_seed=None):
+                 scatter_seed=None, width=None, lookahead=0):
     """Build a pool + tables as the engine would: block 0 is trash, each
     slot's tokens [0, fill) land at (table[slot, p // bs], p % bs). With
     ``scatter_seed`` the physical block ids are shuffled (fragmented pool,
-    as after many alloc/free cycles)."""
+    as after many alloc/free cycles). ``width`` sets the table width (the
+    engine's ``max_seq_len / bs``); ``lookahead`` more blocks per slot are
+    allocated past its tokens, their positions wiped (−1) as ``begin_slot``
+    leaves them and their K/V NaN, so a kernel that computed with one
+    would return NaN."""
     b = len(fills)
-    m = max(-(-f // bs) for f in fills)
-    blocks_needed = sum(-(-f // bs) for f in fills)
-    n = blocks_needed + 1
+    m = width or max(-(-f // bs) for f in fills)
+    held = [min(m, -(-f // bs) + lookahead) for f in fills]
+    n = sum(held) + 1
     k = jax.random.normal(rng[0], (n, bs, kv, hd)).astype(dtype)
     v = jax.random.normal(rng[1], (n, bs, kv, hd)).astype(dtype)
     order = list(range(1, n))
@@ -39,9 +44,12 @@ def _paged_cache(rng, kv, hd, bs, fills, *, dtype=jnp.float32,
     bt = np.full((b, m), -1, np.int32)
     it = iter(order)
     for s, fill in enumerate(fills):
-        for j in range(-(-fill // bs)):
+        for j in range(held[s]):
             blk = next(it)
             bt[s, j] = blk
+            if j * bs >= fill:
+                k = k.at[blk].set(jnp.nan)
+                v = v.at[blk].set(jnp.nan)
             for o in range(bs):
                 p = j * bs + o
                 if p < fill:
@@ -166,3 +174,98 @@ def test_ops_dispatch_wrapper():
                               interpret=True)
     b = ops.paged_decode_attn(q, k, v, q_pos, pos, bt, use_kernel=False)
     assert float(jnp.max(jnp.abs(a - b))) < 1e-4
+
+
+LIVE_CASES = [
+    # (h, kv, hd, bs, window, fills, chunk, width, lookahead, dtype, freed)
+    # engine-wide tables (width = max_seq_len / bs) with look-ahead blocks,
+    # contexts from one token to the full table
+    (4, 2, 32, 16, None, (1, 100, 256), 1, 16, 2, "float32", ()),
+    # a row whose table is all −1 (a freed slot with a stale position)
+    (4, 2, 32, 16, None, (40, 64, 17), 1, 16, 1, "float32", (1,)),
+    # table width not a multiple of the pages per compute block
+    (4, 2, 32, 16, None, (150, 208), 1, 13, 1, "float32", ()),
+    # fewer live pages than a compute block holds
+    (3, 1, 32, 16, None, (5, 20, 33), 1, 16, 3, "float32", ()),
+    # chunk queries at positions 120..135 cross the 128-key block edge
+    (4, 2, 32, 16, None, (136, 64), 16, 16, 2, "float32", ()),
+    # a window whose first visible page is past the table's start
+    (4, 2, 32, 16, 40, (200, 90), 1, 16, 1, "float32", ()),
+    (8, 2, 64, 8, 24, (150, 63), 5, 32, 2, "float32", ()),
+    # bf16 pools
+    (8, 2, 64, 16, None, (1, 129, 256), 1, 16, 2, "bfloat16", ()),
+    (9, 3, 64, 16, None, (70, 200), 8, 16, 1, "bfloat16", ()),
+]
+
+
+@pytest.mark.parametrize("case", LIVE_CASES, ids=[str(c) for c in LIVE_CASES])
+def test_paged_kernel_visits_live_pages(case):
+    """Engine-shaped tables: the kernel reads only each row's live pages,
+    several per compute block, and matches the oracle. Look-ahead blocks
+    hold NaN, so computing with one would show; the oracle is given the
+    table without them, which masks the same keys."""
+    h, kv, hd, bs, window, fills, chunk, width, lookahead, dtype, freed = \
+        case
+    dtype = jnp.dtype(dtype)
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    q = jax.random.normal(ks[0], (len(fills), chunk, h, hd)).astype(dtype)
+    k, v, pos, bt, _ = _paged_cache(ks[1:], kv, hd, bs, fills, dtype=dtype,
+                                    width=width, lookahead=lookahead)
+    q_pos = jnp.asarray([f - chunk for f in fills], jnp.int32)
+    for row in freed:
+        bt = bt.at[row].set(-1)
+    live = jnp.where(pos[jnp.maximum(bt, 0)].max(axis=2) >= 0, bt, -1)
+    out = paged_decode_attention(q, k, v, q_pos, pos, bt, window=window,
+                                 interpret=True)
+    expect = ref.paged_decode_attention_ref(q, k, v, q_pos, pos, live,
+                                            window=window)
+    assert out.shape == q.shape and out.dtype == dtype
+    for row in freed:
+        assert bool(jnp.all(out[row] == 0))
+    gap = jnp.max(jnp.abs(out.astype(jnp.float32)
+                          - expect.astype(jnp.float32)))
+    assert float(gap) < (2e-2 if dtype == jnp.bfloat16 else 1e-4)
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_work_items_fetch_only_live_pages(window):
+    """Replay the pipeline over ``_work_items``: an input copies a block at
+    the first step and wherever its block index changes. Every block
+    copied is one some row's live range ``[lo, hi)`` uses, the steps that
+    compute are exactly the live, allocated entries, and a row without
+    any takes one step."""
+    bs, pages, m = 16, 8, 16
+    rng = np.random.default_rng(0)
+    last = np.array([0, 17, 130, 255, 90, 40])          # last query positions
+    bt = np.full((len(last), m), -1, np.int32)
+    ids = iter(rng.permutation(np.arange(1, 200)))
+    for r, p in enumerate(last):
+        for j in range(min(m, p // bs + 3)):             # look-ahead blocks
+            bt[r, j] = next(ids)
+    bt[4] = -1                                           # a freed row
+    lo, hi = dattn.live_pages(jnp.asarray(last - 3), jnp.asarray(last), bs,
+                              m, window)
+    count, slot, use, held = dattn._work_items(jnp.asarray(bt), lo, hi,
+                                                pages)
+    count, slot = int(count[0]), np.asarray(slot)
+    use, held = np.asarray(use)[:count], np.asarray(held)[:count]
+    lo, hi = np.asarray(lo), np.asarray(hi)
+    blocks = np.maximum(-(-(hi - lo) // pages), 1)
+    assert count == blocks.sum()
+    assert list(slot[:count]) == [r for r in range(len(last))
+                                  for _ in range(blocks[r])]
+    assert (slot[count:] == -1).all()
+    first_item = np.cumsum(blocks) - blocks
+    want = {(int(first_item[r]) + (j - lo[r]) // pages, (j - lo[r]) % pages,
+             int(bt[r, j]))
+            for r in range(len(last)) for j in range(lo[r], hi[r])
+            if bt[r, j] >= 0}
+    used = {(i, p, int(held[i, p])) for i in range(count)
+            for p in range(pages) if use[i, p]}
+    assert used == want
+    live_blocks = {blk for *_, blk in want}
+    assert live_blocks
+    for p in range(pages):
+        seq = list(held[:, p])
+        copied = {seq[0]} | {b for a, b in zip(seq, seq[1:]) if a != b}
+        assert copied <= live_blocks

@@ -88,6 +88,27 @@ def test_paged_decode_attention_compiles(one_chip, arch, t):
              ((SLOTS, TABLE_WIDTH), i32))
 
 
+# the benchmark cells' paged decode: (slots, pool blocks) at table width 128
+# (max_seq_len 2048 in blocks of 16); T = 256 is their prefill chunk
+CELL_POOLS = ((12, 1600), (32, 4097))
+
+
+@pytest.mark.parametrize("t", (1, 256))
+@pytest.mark.parametrize("slots,blocks", CELL_POOLS)
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_paged_decode_attention_compiles_at_cell_shapes(one_chip, arch,
+                                                        slots, blocks, t):
+    h, kv, hd = WIDTHS[arch]
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool = (blocks, BLOCK_SIZE, kv, hd)
+    _compile(lambda q, k, v, qp, kp, bt: paged_decode_attention(
+                 q, k, v, qp, kp, bt),
+             one_chip,
+             ((slots, t, h, hd), bf), (pool, bf), (pool, bf),
+             ((slots,), i32), ((blocks, BLOCK_SIZE), i32),
+             ((slots, 2048 // BLOCK_SIZE), i32))
+
+
 # Kernels no serving path calls (nothing in src/ reaches ops.attention,
 # ops.rglru or ops.gate); the TPU compiler still refuses them.
 @pytest.mark.xfail(strict=True, raises=ValueError,

@@ -171,7 +171,37 @@ def test_kv_gauges_match_the_allocator(engine):
         seen += bool(prompts)
     assert seen > 0
     assert tracing.steps(t0, time.perf_counter())[-1].gauges == {
-        "kv_blocks_committed": 0, "kv_blocks_live": 0, "slots_decoding": 0}
+        "kv_blocks_committed": 0, "kv_blocks_live": 0, "slots_decoding": 0,
+        "attn_pages_visited": 0}
+
+
+def test_attn_pages_visited_counts_each_decoding_slots_live_pages(engine):
+    rng = np.random.default_rng(4)
+    for n in PROMPTS:
+        engine.submit(rng.integers(0, 100, n), max_new_tokens=MAX_NEW)
+    grid = engine.batch_slots * engine.backend.blocks_per_slot
+    seen = 0
+    while engine.pending:
+        t0 = time.perf_counter()
+        engine.step()
+        g = tracing.steps(t0, time.perf_counter())[-1].gauges
+        steps = np.asarray(engine._state["steps"])
+        # the position of each decoding slot's latest token
+        pos = [len(r.prompt) + int(steps[s]) - 1
+               for s, r in engine._slots.items()]
+        assert g["attn_pages_visited"] == sum(-(-(p + 1) // BLOCK)
+                                              for p in pos)
+        assert g["attn_pages_visited"] <= grid
+        seen += bool(pos)
+    assert seen > 0
+
+
+def test_attn_pages_visited_reads_zero_on_the_ring(lm_params):
+    lm, params = lm_params
+    eng = ServingEngine(lm, params, batch_slots=4, max_seq_len=64,
+                        cache_backend="ring")
+    steps = drain(eng)
+    assert steps and all(s.gauges["attn_pages_visited"] == 0 for s in steps)
 
 
 def test_metrics_report_the_ring_totals(engine):
