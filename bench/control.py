@@ -6,11 +6,12 @@
 For each seed, in one process: a run of the cell at its own load for a
 short window (the timed path as ``bench/run.py`` drives it), then, on the
 sampled requests, the program's widest logit gap under the float32
-reference (the lower reading) and, at the same served positions, the
-widest gap of the tokens that the float8 control puts first (the upper
-reading), with whether the control, put through the same comparison,
-comes out correct (it must not). The benchmark's own runs never run the
-control. Prints one JSON line per seed and a summary line.
+reference of the configuration's family (the lower reading) and, at the
+same served positions, the widest gap of the tokens that the family's
+float8 control puts first (the upper reading), with whether the control,
+put through the same comparison, comes out correct (it must not). The
+benchmark's own runs never run the control. Prints one JSON line per seed
+and a summary line.
 """
 from __future__ import annotations
 

@@ -3,18 +3,26 @@
 ``BENCHMARK.json`` names each cell's configuration and traffic mix. The
 harness finds everything else by those names:
 
-- ``bench/configs/<config>.json``: the published configuration, as run;
+- ``bench/configs/<config>.json``: the published configuration, as run,
+  with the ``family`` that knows its architecture;
+- ``bench/families/<family>/``: everything that depends on the layer
+  equations (see ``family_of``): the program's config, the seeded weights
+  and the program's tree over them, the float32 reference and its float8
+  control, and the operation counts ``mfu`` and the kernel rooflines read;
 - ``bench/traffic/<mix>.json``: the traffic mix (``bench/load.py``);
 - ``bench/cells/<cell>.json``: what the deployment states to the engine
   (slots, ``max_seq_len``, KV backend and pool, prefill chunk) and the
   limits of the correctness check;
 - ``bench/metrics/<metric>.py``: one reader per metric (``read(run)``).
 
-A run: make the weights from the seed on the device, build
+A run: make the family's weights from the seed on the device, build
 ``ServingEngine`` behind ``ServingGateway`` (through ``StepProxy``, the
 benchmark's span around each engine step), warm every program the cell
 uses, drive the seeded traffic for the window, then compare a sample of
-the served streams with the float32 reference (``bench/reference.py``).
+the served streams with the family's float32 reference. The families,
+configurations and ``BENCHMARK.json`` are found under the benchmark root
+a run is given; this file and the metric readers name no architecture's
+sizes.
 """
 from __future__ import annotations
 
@@ -25,7 +33,9 @@ import importlib.util
 import json
 import math
 import os
+import sys
 import time
+import types
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -92,22 +102,42 @@ def metrics_for(bench: dict, workload: str, trace: bool) -> List[dict]:
             if "workloads" not in m or workload in m["workloads"]]
 
 
-def program_config(arch: dict, dtype: Optional[str] = None):
-    """The program's ``ModelConfig`` for a configuration file: the preset
-    the file names, with every published size taken from the file."""
-    from repro.configs import SWIGLU, dense_stages, get_config
-    if not arch.get("tie_word_embeddings"):
-        raise ValueError("the benchmark's weights assume a tied head")
-    n = arch["num_hidden_layers"]
-    return get_config(arch["program_config"]).with_overrides(
-        num_layers=n, stages=dense_stages(n, mlp=SWIGLU),
-        d_model=arch["hidden_size"], num_heads=arch["num_attention_heads"],
-        num_kv_heads=arch["num_key_value_heads"],
-        head_dim=arch.get("head_dim") or 0, d_ff=arch["intermediate_size"],
-        vocab_size=arch["vocab_size"], rope_theta=float(arch["rope_theta"]),
-        rms_eps=float(arch["rms_norm_eps"]),
-        use_qk_norm=bool(arch.get("qk_norm")), tie_embeddings=True,
-        param_dtype=dtype or arch["served_dtype"])
+FAMILY_API = ("program_config", "make", "program_params", "gaps",
+              "token_flops", "prompt_flops")
+
+
+def family_of(arch: dict, root: str = ROOT) -> types.ModuleType:
+    """The family package ``<root>/bench/families/<family>/`` that the
+    configuration ``arch`` names, loaded once per directory. It gives
+    ``FAMILY_API`` and, where its layers call the paged GQA decode kernel,
+    ``paged_attn_step(arch, ctxs) -> (flops, bytes)``."""
+    name = arch.get("family")
+    path = os.path.join(os.path.abspath(root), "bench", "families",
+                        str(name))
+    init = os.path.join(path, "__init__.py")
+    if not name or not os.path.isfile(init):
+        files = [c["file"] for c in benchmark(root)["configs"]
+                 if c["name"] == arch.get("name")]
+        raise LookupError(
+            f"{files[0] if files else arch.get('name')!r} names family "
+            f"{name!r}, but there is no {init}")
+    modname = "bench_family_" + "".join(
+        c if c.isalnum() else "_" for c in path)
+    mod = sys.modules.get(modname)
+    if mod is None:
+        spec = importlib.util.spec_from_file_location(
+            modname, init, submodule_search_locations=[path])
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[modname] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[modname]
+            raise
+    missing = [f for f in FAMILY_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise LookupError(f"family {name!r} ({init}) lacks {missing}")
+    return mod
 
 
 # ---------------------------------------------------------------------------
@@ -149,6 +179,7 @@ class Step:
 class Run:
     workload: str
     arch: dict
+    family: types.ModuleType
     cell: dict
     mix: dict
     seconds: float
@@ -440,13 +471,13 @@ def sample(recs: List[Rec], n: int, seed: int) -> List[Rec]:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def compare(w, arch: dict, recs: List[Rec], cell: dict,
+def compare(family, w, arch: dict, recs: List[Rec], cell: dict,
             control: bool = False):
-    """The numbers the check compares, each beside its limit. With
-    ``control``, returns ``(checks, control_checks)``: the second holds the
-    same numbers for the float8 control put in the program's place, read
-    at the same served positions: the gap of the token it puts first."""
-    from bench import reference
+    """The numbers the check compares, each beside its limit, from the
+    family's reference. With ``control``, returns ``(checks,
+    control_checks)``: the second holds the same numbers for the float8
+    control put in the program's place, read at the same served positions:
+    the gap of the token it puts first."""
     bad = 0
     worst = ctl_worst = 0.0
     served = 0
@@ -458,7 +489,7 @@ def compare(w, arch: dict, recs: List[Rec], cell: dict,
             bad += 1
             continue
         seq = np.concatenate([r.prompt, toks])
-        gaps, ctl = reference.gaps(w, arch, seq, r.prompt_len, control)
+        gaps, ctl = family.gaps(w, arch, seq, r.prompt_len, control)
         worst = max(worst, float(np.max(gaps)))
         if control:
             ctl_worst = max(ctl_worst, float(np.max(ctl)))
@@ -490,22 +521,24 @@ def passed(checks: dict) -> bool:
 # one run
 # ---------------------------------------------------------------------------
 
-def build(arch: dict, cell: dict, seed: int, log: Callable[[str], None]):
-    """Weights from the seed, the program's parameter tree over them, and
-    the engine the cell states. Returns (weights, lm, engine, facts)."""
+def build(family, arch: dict, cell: dict, seed: int,
+          log: Callable[[str], None]):
+    """The family's weights from the seed, the program's parameter tree
+    over them, and the engine the cell states. Returns (weights, lm,
+    engine, facts)."""
     import jax
-    from bench import weights
+    from bench.weights import check_tree
     from repro.models.model import LM
     from repro.serving import ServingEngine
 
-    cfg = program_config(arch)
+    cfg = family.program_config(arch)
     lm = LM(cfg)
     dev = jax.devices()[0]
     t0 = time.perf_counter()
-    w = jax.block_until_ready(weights.make(arch, cfg.padded_vocab, seed,
-                                           dtype=cfg.param_dtype))
-    params = weights.program_params(w, arch)
-    weights.check_tree(params, lm.abstract()[0])
+    w = jax.block_until_ready(family.make(arch, cfg.padded_vocab, seed,
+                                          dtype=cfg.param_dtype))
+    params = family.program_params(w, arch)
+    check_tree(params, lm.abstract()[0])
     facts = {"weights_s": time.perf_counter() - t0,
              "weight_bytes": sum(x.nbytes for x in jax.tree.leaves(w))}
     before = (dev.memory_stats() or {}).get("bytes_in_use", 0)
@@ -527,14 +560,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              out_dir: str, bench: Optional[dict] = None,
              arch: Optional[dict] = None, cell: Optional[dict] = None,
              mix: Optional[dict] = None, device: Optional[dict] = None,
-             control: bool = False) -> dict:
-    """Run ``workload`` once and return the result line (a dict)."""
+             control: bool = False, root: str = ROOT) -> dict:
+    """Run ``workload`` once and return the result line (a dict).
+    ``BENCHMARK.json``, the configuration and its family are read under
+    ``root`` where not given."""
     import jax
     from repro.serving import ServingGateway
 
-    bench = bench or benchmark()
+    bench = bench or benchmark(root)
     entry = _entry(bench["workloads"], workload, "workload")
-    arch = arch or arch_of(entry["config"])
+    arch = arch or arch_of(entry["config"], root)
+    family = family_of(arch, root)
     cell = cell or cell_of(workload)
     mix = mix or load.load_mix(entry["traffic"])
     dev = jax.devices()[0]
@@ -543,7 +579,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     peaks = peaks_of(device["kind"])
     counter = CompileCounter()
 
-    w, lm, engine, facts = build(arch, cell, seed, log)
+    w, lm, engine, facts = build(family, arch, cell, seed, log)
     t0 = time.perf_counter()
     engine.warm_compile()
     facts["warm_s"] = time.perf_counter() - t0
@@ -608,8 +644,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                  window_compiles=counter.get("window", "executables"),
                  window_traces=counter.get("window", "traces"))
 
-    run = Run(workload, arch, cell, mix, seconds, start, start + seconds,
-              setup_s, recs, proxy.steps, peaks)
+    run = Run(workload, arch, family, cell, mix, seconds, start,
+              start + seconds, setup_s, recs, proxy.steps, peaks)
     if tracer is not None and tracer.t_on is not None:
         from bench import trace as trace_mod
         run.trace = trace_mod.reduce_dir(
@@ -627,7 +663,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     t0 = time.perf_counter()
     done = finished_in_window(recs, run.start, run.end)
     checked = sample(done, cell["check_requests"], seed)
-    checks = compare(w, arch, checked, cell, control)
+    checks = compare(family, w, arch, checked, cell, control)
     if control:
         checks, ctl_checks = checks
     facts["requests_done"] = len(done)

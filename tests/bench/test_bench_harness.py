@@ -16,13 +16,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, ROOT)
 
 from bench import harness  # noqa: E402
-from bench.harness import arch_of, program_config  # noqa: E402
+from bench.harness import arch_of, family_of  # noqa: E402
 
 TINY = "tiny.decode"
 
 
 def tiny_arch():
-    cfg = program_config(arch_of("qwen3-4b")).reduced()
+    cfg = family_of(arch_of("qwen3-4b")).program_config(
+        arch_of("qwen3-4b")).reduced()
     arch = dict(arch_of("qwen3-4b"))
     arch.update(hidden_size=cfg.d_model, num_attention_heads=cfg.num_heads,
                 num_key_value_heads=cfg.num_kv_heads, head_dim=64,
@@ -183,13 +184,13 @@ def test_metrics_are_found_by_name(tmp_path):
 
 
 def test_gap_of_a_served_stream_uses_the_tokens_given(tmp_path):
-    from bench import weights
     arch = tiny_arch()
-    cfg = program_config(arch)
-    ws = weights.make(arch, cfg.padded_vocab, 1, dtype=jnp.float32)
+    family = family_of(arch)
+    cfg = family.program_config(arch)
+    ws = family.make(arch, cfg.padded_vocab, 1, dtype=jnp.float32)
     rec = harness.Rec(0, 0.0, 5, 3, np.arange(5, dtype=np.int32))
     rec.tokens, rec.status, rec.output = [1, 2], "done", np.array([1, 2])
-    checks = harness.compare(ws, arch, [rec], CELL)
+    checks = harness.compare(family, ws, arch, [rec], CELL)
     assert checks["bad_streams"]["value"] == 1   # 2 tokens of 3 served
 
 

@@ -1,6 +1,6 @@
-"""The benchmark's float32 reference against the program's ``LM.forward``,
-on the reduced variants of both benchmark configurations, with weights the
-benchmark made and mapped into the program's tree."""
+"""The dense_gqa family's float32 reference against the program's
+``LM.forward``, on the reduced variants of both benchmark configurations,
+with weights the family made and mapped into the program's tree."""
 import os
 import sys
 
@@ -13,15 +13,15 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from bench import reference, weights  # noqa: E402
-from bench.harness import arch_of, program_config  # noqa: E402
+from bench.harness import arch_of, family_of  # noqa: E402
+from bench.weights import check_tree  # noqa: E402
 from repro.models.model import LM  # noqa: E402
 
 
 def small(name, layers=2):
     """A reduced copy of the benchmark configuration ``name``: the
     program's ``reduced()`` widths at ``layers`` layers, as a config dict."""
-    cfg = program_config(arch_of(name)).reduced()
+    cfg = family_of(arch_of(name)).program_config(arch_of(name)).reduced()
     arch = dict(arch_of(name))
     arch.update(hidden_size=cfg.d_model, num_attention_heads=cfg.num_heads,
                 num_key_value_heads=cfg.num_kv_heads, head_dim=64,
@@ -33,12 +33,13 @@ def small(name, layers=2):
 @pytest.mark.parametrize("name", ["qwen3-4b", "smollm-135m"])
 def test_reference_matches_program_forward(name):
     arch = small(name)
-    cfg = program_config(arch, dtype="float32")
+    family = family_of(arch)
+    cfg = family.program_config(arch, dtype="float32")
     lm = LM(cfg)
-    w = weights.make(arch, cfg.padded_vocab, seed=2**33 + 5,
-                     dtype=jnp.float32)
-    params = weights.program_params(w, arch)
-    weights.check_tree(params, lm.abstract()[0])
+    w = family.make(arch, cfg.padded_vocab, seed=2**33 + 5,
+                    dtype=jnp.float32)
+    params = family.program_params(w, arch)
+    check_tree(params, lm.abstract()[0])
     tokens = np.random.default_rng(0).integers(
         0, arch["vocab_size"], size=40).astype(np.int32)
     with jax.default_matmul_precision("highest"):
@@ -46,7 +47,7 @@ def test_reference_matches_program_forward(name):
     logits = np.asarray(logits[0, :, :arch["vocab_size"]], np.float64)
     # every position's gap agrees with the program's own logits
     first = 10
-    served, _ = reference.gaps(w, arch, tokens, first)
+    served, _ = family.gaps(w, arch, tokens, first)
     rows = logits[first - 1:-1]
     want = rows.max(-1) - rows[np.arange(len(rows)), tokens[first:]]
     assert served.shape == (len(tokens) - first,)
@@ -56,20 +57,22 @@ def test_reference_matches_program_forward(name):
 
 def test_float8_control_departs_from_reference():
     arch = small("qwen3-4b")
-    cfg = program_config(arch, dtype="float32")
-    w = weights.make(arch, cfg.padded_vocab, seed=3)
+    family = family_of(arch)
+    cfg = family.program_config(arch, dtype="float32")
+    w = family.make(arch, cfg.padded_vocab, seed=3)
     tokens = np.random.default_rng(1).integers(
         0, arch["vocab_size"], size=300).astype(np.int32)
-    served, ctl = reference.gaps(w, arch, tokens, 1, control=True)
+    served, ctl = family.gaps(w, arch, tokens, 1, control=True)
     assert ctl.shape == served.shape
     assert np.all(ctl >= 0) and np.max(ctl) > 0
 
 
 def test_weights_depend_on_large_seeds():
     arch = small("smollm-135m")
-    a = weights.make(arch, 512, seed=2**40 + 1)
-    b = weights.make(arch, 512, seed=2**40 + 1)
-    c = weights.make(arch, 512, seed=2**40 + 2)
+    family = family_of(arch)
+    a = family.make(arch, 512, seed=2**40 + 1)
+    b = family.make(arch, 512, seed=2**40 + 1)
+    c = family.make(arch, 512, seed=2**40 + 2)
     assert np.array_equal(np.asarray(a["wq"], np.float32),
                           np.asarray(b["wq"], np.float32))
     assert not np.array_equal(np.asarray(a["wq"], np.float32),
